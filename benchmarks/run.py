@@ -41,6 +41,7 @@ from benchmarks import (  # noqa: E402
     roofline_table,
 )
 from repro.federated import scenarios  # noqa: E402
+from repro.utils.compile_cache import enable_compile_cache  # noqa: E402
 
 BENCHES = {
     "fig1a": fig1a_epsilon.run,
@@ -82,6 +83,7 @@ def main(argv=None) -> None:
                          "checkpoints and re-run everything (files are "
                          "overwritten)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(BENCHES)
     payloads = {}
     for name in names:

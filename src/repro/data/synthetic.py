@@ -7,7 +7,8 @@ serve the LM architectures.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
+from typing import Optional
 
 import numpy as np
 
@@ -17,37 +18,48 @@ class ClassificationData:
     x: np.ndarray  # (N, H, W, C) float32
     y: np.ndarray  # (N,) int32
     n_classes: int
+    # (n_classes, H, W, C) class templates: the task itself. A held-out
+    # split passes `task=` to draw new samples of the same templates.
+    templates: Optional[np.ndarray] = field(
+        default=None, repr=False, compare=False)
 
     @property
     def n(self) -> int:
         return len(self.y)
 
 
-def _teacher_features(rng, n, hw, c, n_classes, y):
-    """Class-conditional images: smooth class template + structured noise."""
+def _classification(n, seed, hw, c, n_classes, task):
+    """Class-conditional images: smooth class template + structured noise.
+    The templates come from `task` when given (a held-out split of that
+    task), else from `seed`."""
+    rng = np.random.default_rng(seed)
+    y = rng.integers(0, n_classes, n).astype(np.int32)
     h, w = hw
-    # Low-frequency class templates upsampled from 7x7 seeds.
-    seeds = rng.normal(0.0, 1.0, (n_classes, 7, 7, c)).astype(np.float32)
-    reps = (int(np.ceil(h / 7)), int(np.ceil(w / 7)))
-    templates = np.kron(seeds, np.ones((1, *reps, 1), np.float32))[:, :h, :w, :]
+    if task is None:
+        # Low-frequency class templates upsampled from 7x7 seeds.
+        seeds = rng.normal(0.0, 1.0, (n_classes, 7, 7, c)).astype(np.float32)
+        reps = (int(np.ceil(h / 7)), int(np.ceil(w / 7)))
+        templates = np.kron(seeds, np.ones((1, *reps, 1), np.float32))[
+            :, :h, :w, :]
+    else:
+        templates = task.templates
     x = templates[y]
     x = x + rng.normal(0.0, 0.8, x.shape).astype(np.float32)
     # Mild nonlinearity so linear probes don't trivially solve it.
-    return np.tanh(x).astype(np.float32)
+    return ClassificationData(x=np.tanh(x).astype(np.float32), y=y,
+                              n_classes=n_classes, templates=templates)
 
 
-def make_mnist_like(n: int = 10_000, seed: int = 0) -> ClassificationData:
-    rng = np.random.default_rng(seed)
-    y = rng.integers(0, 10, n).astype(np.int32)
-    x = _teacher_features(rng, n, (28, 28), 1, 10, y)
-    return ClassificationData(x=x, y=y, n_classes=10)
+def make_mnist_like(n: int = 10_000, seed: int = 0,
+                    task: Optional[ClassificationData] = None
+                    ) -> ClassificationData:
+    return _classification(n, seed, (28, 28), 1, 10, task)
 
 
-def make_cifar_like(n: int = 10_000, seed: int = 0) -> ClassificationData:
-    rng = np.random.default_rng(seed)
-    y = rng.integers(0, 10, n).astype(np.int32)
-    x = _teacher_features(rng, n, (32, 32), 3, 10, y)
-    return ClassificationData(x=x, y=y, n_classes=10)
+def make_cifar_like(n: int = 10_000, seed: int = 0,
+                    task: Optional[ClassificationData] = None
+                    ) -> ClassificationData:
+    return _classification(n, seed, (32, 32), 3, 10, task)
 
 
 def make_token_stream(

@@ -479,7 +479,7 @@ class ExperimentSpec:
 
         eval_fn = eval_batch_fn = None
         if self.with_eval:
-            test = make(self.n_test, seed=self.seed + 1)
+            test = make(self.n_test, seed=self.seed + 1, task=data)
             xb, yb = jnp.asarray(test.x), jnp.asarray(test.y)
 
             @jax.jit

@@ -96,18 +96,6 @@ def envelope_local_steps_fn(loss_fn: Callable, opt: Optimizer):
     return run
 
 
-def _get_shard_map():
-    """shard_map + its replication-check kwarg across jax versions: the
-    top-level export with check_vma (jax >= 0.8) or the experimental one
-    with check_rep (jax < 0.8, e.g. the 0.4.x CPU container)."""
-    try:
-        from jax import shard_map as sm
-        return sm, {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm, {"check_rep": False}
-
-
 def _participation_weights(weights, mask):
     """FedAvg weights renormalized over the round's participating clients.
 
@@ -244,8 +232,6 @@ def _int8_shardmap_sync(mesh, param_specs_tree, client_axes):
     all-reduce at one extra rounding step (unbiased via the stochastic
     quantizer semantics; deterministic rounding here since the round-step
     PRNG lives outside the sync)."""
-    _shard_map, _sm_kw = _get_shard_map()
-
     axis = client_axes if len(client_axes) > 1 else client_axes[0]
 
     def sync(new_p, old_p, weights):
@@ -270,8 +256,8 @@ def _int8_shardmap_sync(mesh, param_specs_tree, client_axes):
                 return out.reshape(o_loc.shape).astype(n_loc.dtype)
 
             in_specs = (spec, spec, jax.sharding.PartitionSpec())
-            return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=spec, **_sm_kw)(
+            return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=spec, check_vma=False)(
                 new, old, weights)
 
         return jax.tree.map(leaf, new_p, old_p, param_specs_tree)
@@ -288,8 +274,6 @@ def _psum_shardmap_sync(mesh, param_specs_tree, client_axes):
     client-axis contraction as a FULL all-gather of the stacked fp32
     weights (measured 197 GB/leaf on llava-next-34b — EXPERIMENTS.md
     §Perf B). A pinned psum moves 2x the leaf shard instead."""
-    _shard_map, _sm_kw = _get_shard_map()
-
     axes = tuple(client_axes)
 
     def sync(new_p, weights):
@@ -314,8 +298,8 @@ def _psum_shardmap_sync(mesh, param_specs_tree, client_axes):
                 return jnp.broadcast_to(agg, n_loc.shape).astype(n_loc.dtype)
 
             in_specs = (spec, jax.sharding.PartitionSpec())
-            return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=spec, **_sm_kw)(new, weights)
+            return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=spec, check_vma=False)(new, weights)
 
         return jax.tree.map(leaf, new_p, param_specs_tree)
 

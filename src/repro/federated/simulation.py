@@ -709,7 +709,7 @@ class Simulator:
                 "stateless local optimizer (plain SGD)")
         # Sharded client axis: FedAvg aggregation as a shard_map psum
         # over a 1-D 'clients' device mesh.
-        self._mesh = self._param_specs = None
+        self._mesh = self._param_specs = self._carry = None
         self.shard_clients = bool(shard_clients)
         if shard_clients:
             if backend != "scan":
@@ -730,6 +730,11 @@ class Simulator:
             spec = jax.sharding.PartitionSpec("clients")
             self._param_specs = jax.tree.map(
                 lambda _: spec, self._init_params)
+            # Where the scan chunk's carry lives, in and out: params/opt
+            # lanes split over 'clients', the key replicated.
+            lanes = jax.sharding.NamedSharding(self._mesh, spec)
+            self._carry = (lanes, lanes, jax.sharding.NamedSharding(
+                self._mesh, jax.sharding.PartitionSpec()))
         # Static per-client compute times (Eq. 4); uplink times depend on
         # the realized per-round channel and are computed per round.
         self._t_cp_clients = delay.per_client_compute_time(
@@ -1149,6 +1154,15 @@ class Simulator:
     def _chunk_call(self, params_C, opt_C, key, weights, t_cp_arg, xs):
         """One compiled chunk dispatch, threading the trivial envelope
         masks on envelope-form sims."""
+        if self._carry is not None:
+            # A trace keys on each argument's placement, so a carry from
+            # init() or from a load_state checkpoint (numpy leaves) is put
+            # where the previous chunk leaves it: one compile per run.
+            # `in_shardings` on the jit alone does not do this (the first
+            # chunk still traced apart). On a carry that is already there
+            # this is a no-op.
+            params_C, opt_C, key = jax.device_put((params_C, opt_C, key),
+                                                  self._carry)
         if self._envelope:
             return self._chunk_fn(params_C, opt_C, key, weights, t_cp_arg,
                                   self._data_dev, xs, self._trivial_env())

@@ -41,8 +41,8 @@ def _attn_kernel(
 
     def body(kb, carry):
         m_prev, l_prev, acc = carry
-        k_blk = pl.load(k_ref, (pl.ds(kb * block_k, block_k), slice(None)))
-        v_blk = pl.load(v_ref, (pl.ds(kb * block_k, block_k), slice(None)))
+        k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
+        v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
         s = (q @ k_blk.astype(jnp.float32).T) * scale  # (bq, bk)
         k_pos = kb * block_k + jax.lax.iota(jnp.int32, block_k)
         mask = jnp.ones((block_q, block_k), jnp.bool_)

@@ -14,12 +14,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 from repro.kernels.flash_attention.ref import attention_ref
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -33,7 +30,7 @@ def _flash_bh(q, k, v, causal, window, block):
     vp = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0)))
     out = flash_attention_kernel(
         qp, kp, vp, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=not _is_tpu())
+        block_q=block_q, block_k=block_k, interpret=interpret_mode())
     return out[:, :Sq]
 
 

@@ -3,15 +3,11 @@ from __future__ import annotations
 
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.quantize.kernel import quantize_kernel
 from repro.kernels.quantize.ref import dequantize_ref, stochastic_noise
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def quantize(x: jnp.ndarray, key, block_r: int = 256) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -25,7 +21,7 @@ def quantize(x: jnp.ndarray, key, block_r: int = 256) -> Tuple[jnp.ndarray, jnp.
         x = jnp.pad(x, ((0, pad), (0, 0)))
         u = jnp.pad(u, ((0, pad), (0, 0)))
     q, s = quantize_kernel(x, u, block_r=min(block_r, x.shape[0]),
-                           interpret=not _is_tpu())
+                           interpret=interpret_mode())
     return q[:R], s[:R]
 
 

@@ -4,15 +4,11 @@ from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.selective_scan.kernel import selective_scan_kernel
 from repro.kernels.selective_scan.ref import selective_scan_ref
-
-
-def _is_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pick_block_d(Dm: int) -> int:
@@ -45,5 +41,5 @@ def selective_scan(
         B, C = (jnp.pad(t, ((0, 0), (0, pad_s), (0, 0))) for t in (B, C))
     y, h = selective_scan_kernel(
         x, dt, A, B, C, D, chunk=L, block_d=_pick_block_d(Dm),
-        interpret=not _is_tpu())
+        interpret=interpret_mode())
     return y[:, :S], h

@@ -158,8 +158,6 @@ def analyse(lowered, compiled, cfg: ModelConfig, shape: InputShape,
             mesh, V: int) -> Dict:
     n_dev = mesh.devices.size
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax < 0.6 returns [per-module dict]
-        cost = cost[0] if cost else {}
     flops_dev = float(cost.get("flops", 0.0))
     bytes_dev = float(cost.get("bytes accessed", 0.0))
     try:

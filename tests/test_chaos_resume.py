@@ -51,7 +51,8 @@ def test_sigkill_mid_study_then_resume_bit_identical(tmp_path):
     ckpt = str(tmp_path / "ckpt")
     ref = _payload(_study().run())
 
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     child = subprocess.Popen([sys.executable, os.path.abspath(__file__),
                               ckpt], env=env, cwd=REPO,
                              stdout=subprocess.DEVNULL,

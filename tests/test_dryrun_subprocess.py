@@ -13,7 +13,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.mark.parametrize("arch,shape", [("qwen2-0.5b", "decode_32k")])
 def test_dryrun_single_pair(tmp_path, arch, shape):
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
          "--shape", shape, "--mesh", "single", "--out", str(tmp_path)],

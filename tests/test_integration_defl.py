@@ -24,7 +24,7 @@ CAL_CC = ComputeConfig(bits_per_sample=6.8e5)
 @pytest.fixture(scope="module")
 def mnist_setup():
     data = make_mnist_like(600, seed=0)
-    test = make_mnist_like(200, seed=1)
+    test = make_mnist_like(200, seed=1, task=data)
     cfg = cnn.mnist_cnn()
     params = cnn.init_cnn(cfg, jax.random.PRNGKey(0))
     return data, test, cfg, params

@@ -76,7 +76,8 @@ print("OK")
 """
     out = subprocess.run(
         [sys.executable, "-c", code],
-        env=dict(os.environ, PYTHONPATH=os.path.join(repo, "src")),
+        env=dict(os.environ, PYTHONPATH=os.path.join(repo, "src"),
+                 JAX_PLATFORMS="cpu"),
         capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stdout + out.stderr
     assert "OK" in out.stdout

@@ -17,6 +17,10 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _CHILD = r"""
+import dataclasses
+import os
+import tempfile
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,7 +30,7 @@ assert jax.device_count() == 2, jax.devices()
 from repro.configs.base import FedConfig
 from repro.core import delay
 from repro.federated import scenarios
-from repro.federated.simulation import Simulator
+from repro.federated.simulation import Simulator, load_state, save_state
 from repro.optim import sgd
 
 
@@ -59,11 +63,30 @@ def make(shard, K=None, M=6):
 
 def run(sim):
     _, res = sim.run(sim.init(), max_rounds=5, eval_every=2)
+    # One compiled chunk for the whole run, sharded or not: the chunk's
+    # jit places its carry, whatever placement init() gave it.
+    assert sim.trace_count == 1, sim.trace_count
     return res
+
+
+def resumed(sim, path):
+    # A checkpoint comes back as numpy leaves; the resumed run still
+    # compiles once and continues the uninterrupted one.
+    state, first = sim.run(sim.init(), max_rounds=2, eval_every=2)
+    save_state(path, state)
+    _, rest = sim.run(load_state(path), max_rounds=3, eval_every=2)
+    assert sim.trace_count == 1, sim.trace_count
+    return dataclasses.replace(rest, history=first.history + rest.history)
 
 
 for K in (None, 4):
     ref, shd = run(make(False, K)), run(make(True, K))
+    with tempfile.TemporaryDirectory() as d:
+        res = resumed(make(True, K), os.path.join(d, "state.pkl"))
+    for a, b in zip(jax.tree.leaves(shd.params), jax.tree.leaves(res.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert [x.train_loss for x in shd.history] == [
+        x.train_loss for x in res.history]
     for a, b in zip(jax.tree.leaves(ref.params),
                     jax.tree.leaves(shd.params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -81,6 +104,7 @@ for K in (None, 4):
 def test_shardmap_parity_two_virtual_devices():
     env = dict(os.environ,
                PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=2")
     out = subprocess.run([sys.executable, "-c", _CHILD], env=env,
                          capture_output=True, text=True, timeout=900)
