@@ -416,13 +416,17 @@ def build_round_step(
     def round_step(params_C, opt_C, batches, weights, keys=None,
                    mask=None, clock_mask=None, t_cp=None, t_cm=None,
                    env=None):
-        if envelope:
-            new_p, new_s, losses = jax.vmap(
-                local, in_axes=(0, 0, 0, None, None, None))(
-                    params_C, opt_C, batches, env["v_mask"],
-                    env["sample_mask"], env["n_samples"])
-        else:
-            new_p, new_s, losses = jax.vmap(local)(params_C, opt_C, batches)
+        # Named scopes (metadata only) split the compiled round into the
+        # paper's work (local_steps) and talk (aggregate) in a profile.
+        with jax.named_scope("local_steps"):
+            if envelope:
+                new_p, new_s, losses = jax.vmap(
+                    local, in_axes=(0, 0, 0, None, None, None))(
+                        params_C, opt_C, batches, env["v_mask"],
+                        env["sample_mask"], env["n_samples"])
+            else:
+                new_p, new_s, losses = jax.vmap(local)(params_C, opt_C,
+                                                       batches)
         if guard is not None:
             new_p, mask = _guard_clients(guard, new_p, params_C, losses, mask)
         any_p = None
@@ -436,23 +440,25 @@ def build_round_step(
             new_p = _select_participating_state(new_p, params_C, mask)
             new_s = _select_participating_state(new_s, opt_C, mask)
             losses = jnp.where(mask > 0, losses, 0.0)
-        if aggregation == "allreduce":
-            agg_p = _weighted_mean_bcast(new_p, weights)
-        elif aggregation == "allreduce_shardmap":
-            agg_p = psum_sync(new_p, weights)
-        elif aggregation == "int8_gather":
-            agg_p = _int8_gather_mean_bcast(
-                new_p, params_C, weights, key=None)
-        elif aggregation == "int8_stochastic":
-            assert keys is not None, "int8_stochastic needs per-client keys"
-            agg_p = _int8_stochastic_mean_bcast(
-                new_p, params_C, weights, keys, impl)
-        elif aggregation == "int8_shardmap":
-            agg_p = int8_sync(new_p, params_C, weights)
-        else:
-            raise ValueError(aggregation)
-        if any_p is not None:
-            agg_p = _keep_old_params(agg_p, params_C, any_p)
+        with jax.named_scope("aggregate"):
+            if aggregation == "allreduce":
+                agg_p = _weighted_mean_bcast(new_p, weights)
+            elif aggregation == "allreduce_shardmap":
+                agg_p = psum_sync(new_p, weights)
+            elif aggregation == "int8_gather":
+                agg_p = _int8_gather_mean_bcast(
+                    new_p, params_C, weights, key=None)
+            elif aggregation == "int8_stochastic":
+                assert keys is not None, (
+                    "int8_stochastic needs per-client keys")
+                agg_p = _int8_stochastic_mean_bcast(
+                    new_p, params_C, weights, keys, impl)
+            elif aggregation == "int8_shardmap":
+                agg_p = int8_sync(new_p, params_C, weights)
+            else:
+                raise ValueError(aggregation)
+            if any_p is not None:
+                agg_p = _keep_old_params(agg_p, params_C, any_p)
         metrics = {"loss": jnp.tensordot(weights.astype(jnp.float32),
                                          losses, axes=(0, 0)),
                    "per_client_loss": losses}

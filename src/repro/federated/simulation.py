@@ -76,6 +76,12 @@ Three execution backends share the same math:
       implementation; backends agree to fp32 tolerance under a fixed
       seed (bit-for-bit on the quantizer noise — see
       compression.sequential_client_keys).
+
+Each `Simulator.run` call is one profiler span, `defl.run`. Inside it
+the chunked drivers ('scan' and 'async') mark their host steps:
+`defl.materialize`, `defl.chunk_inputs`, `defl.dispatch`, `defl.fetch`,
+`defl.records`, `defl.eval` and `defl.snapshot`. The spans cost nothing
+unless a profiler is running (`jax.profiler.trace`).
 """
 from __future__ import annotations
 
@@ -91,6 +97,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import FedConfig, WirelessConfig
 from repro.core import delay
@@ -1547,14 +1554,17 @@ class Simulator:
         # (_rewind_chunk replays this exact order).
         cohorts = chunk = mask_M = t_cm_M = None
         if self._sampled:
-            cands = stream.draw_cohorts(n)
-            chunk = stream.draw_chunk(n)
+            with TraceAnnotation("defl.draw_cohorts"):
+                cands = stream.draw_cohorts(n)
+            with TraceAnnotation("defl.draw_chunk"):
+                chunk = stream.draw_chunk(n)
             mask_M, t_cm_M = self._chunk_uplink(chunk)
             cohorts = (self._select_cohorts(cands, t_cm_M)
                        if self._spare else cands)
         if self._data_dev is not None:
-            idx = (stack_cohort_indices(iters, cohorts, V) if self._sampled
-                   else stack_chunk_indices(iters, n, V))
+            with TraceAnnotation("defl.batch_indices"):
+                idx = (stack_cohort_indices(iters, cohorts, V)
+                       if self._sampled else stack_chunk_indices(iters, n, V))
             xs = {"idx": pad_env(idx)}
         else:
             if self._sampled:
@@ -1570,7 +1580,8 @@ class Simulator:
         host = {}
         if self.scenario is not None:
             if not self._sampled:
-                chunk = stream.draw_chunk(n)
+                with TraceAnnotation("defl.draw_chunk"):
+                    chunk = stream.draw_chunk(n)
                 # Retransmission sums + deadline exclusion, resolved
                 # M-wide (f64 host twin — see _chunk_uplink).
                 mask_M, t_cm_M = self._chunk_uplink(chunk)
@@ -1899,8 +1910,9 @@ class Simulator:
         device_get per chunk, chunk boundaries at aggregation (round)
         boundaries so eval cadence matches the sync drivers'. A 'round'
         is a buffer fill; max_rounds counts fills."""
-        iters, stream = self._materialize(state)
-        twin = self._async_twin(state)
+        with TraceAnnotation("defl.materialize"):
+            iters, stream = self._materialize(state)
+            twin = self._async_twin(state)
         params_C, opt_C, key = state.params_C, state.opt_C, state.key
         async_c = state.async_c
         bits_acc = float(state.async_host.get("bits_acc", 0.0))
@@ -1910,16 +1922,20 @@ class Simulator:
         done, stop, idle_chunks = 0, False, 0
         while done < max_rounds and not stop:
             n_t = min(eval_every - done % eval_every, max_rounds - done)
-            xs, evs, n_ev = self._async_chunk_inputs(
-                iters, stream, twin, stop_aggs=n_t,
-                max_sim_time=max_sim_time)
-            params_C, opt_C, key, async_c, ys = self._chunk_fn(
-                params_C, opt_C, key, async_c, self._sizes_f32,
-                self._data_dev, xs)
+            with TraceAnnotation("defl.chunk_inputs"):
+                xs, evs, n_ev = self._async_chunk_inputs(
+                    iters, stream, twin, stop_aggs=n_t,
+                    max_sim_time=max_sim_time)
+            with TraceAnnotation("defl.dispatch"):
+                params_C, opt_C, key, async_c, ys = self._chunk_fn(
+                    params_C, opt_C, key, async_c, self._sizes_f32,
+                    self._data_dev, xs)
             # The chunk's only device->host sync, same as the sync scan.
-            ys = jax.device_get(ys)
-            records, bits_acc = self._async_records(
-                ys, evs, n_ev, r0 + done, bits_acc)
+            with TraceAnnotation("defl.fetch"):
+                ys = jax.device_get(ys)
+            with TraceAnnotation("defl.records"):
+                records, bits_acc = self._async_records(
+                    ys, evs, n_ev, r0 + done, bits_acc)
             n_events += n_ev
             history.extend(records)
             done += len(records)
@@ -1940,15 +1956,17 @@ class Simulator:
                                         or done == max_rounds)
             if self.eval_fn and records and (at_boundary or stop):
                 rec = history[-1]
-                ev = self.eval_fn(async_c["params_g"])
+                with TraceAnnotation("defl.eval"):
+                    ev = self.eval_fn(async_c["params_g"])
                 rec.test_acc = float(ev.get("acc", np.nan))
                 rec.test_loss = float(ev.get("loss", np.nan))
                 if (target_acc and rec.test_acc is not None
                         and rec.test_acc >= target_acc):
                     stop = True
-        new_state = self._async_state(
-            state, params_C, opt_C, key, async_c, twin, r0 + done,
-            n_events, bits_acc, iters, stream)
+        with TraceAnnotation("defl.snapshot"):
+            new_state = self._async_state(
+                state, params_C, opt_C, key, async_c, twin, r0 + done,
+                n_events, bits_acc, iters, stream)
         return new_state, SimResult(
             history=history, params=async_c["params_g"],
             label=self.label, fed=self.fed)
@@ -1997,7 +2015,8 @@ class Simulator:
         the first exceeding round, matching the per-round backends; the
         device state is end-of-chunk (documented deviation — the chunk is
         already in flight)."""
-        iters, stream = self._materialize(state)
+        with TraceAnnotation("defl.materialize"):
+            iters, stream = self._materialize(state)
         guard_on = (self._faults is not None
                     and self._faults.divergence_guard)
         # Last-good snapshot for DivergenceError recovery: taken BEFORE
@@ -2023,13 +2042,18 @@ class Simulator:
                 # agree with its round cursor (see below).
                 pre_data = self._snapshot_iters(iters)
                 pre_stream = stream.state() if stream is not None else None
-            xs, host = self._chunk_inputs(iters, stream, R, n)
-            params_C, opt_C, key, ys = self._chunk_call(
-                params_C, opt_C, key, weights, t_cp_arg, xs)
+            with TraceAnnotation("defl.chunk_inputs"):
+                xs, host = self._chunk_inputs(iters, stream, R, n)
+            with TraceAnnotation("defl.dispatch"):
+                params_C, opt_C, key, ys = self._chunk_call(
+                    params_C, opt_C, key, weights, t_cp_arg, xs)
             # The chunk's only device->host sync: one stacked fetch of all
             # per-round scan outputs.
-            ys = jax.device_get(ys)
-            records = self._chunk_records(ys, host, n, r0 + done, sim_time)
+            with TraceAnnotation("defl.fetch"):
+                ys = jax.device_get(ys)
+            with TraceAnnotation("defl.records"):
+                records = self._chunk_records(ys, host, n, r0 + done,
+                                              sim_time)
             if max_sim_time:
                 for j, rec in enumerate(records):
                     if rec.sim_time >= max_sim_time:
@@ -2057,22 +2081,25 @@ class Simulator:
                 checked = self._raise_if_diverged(
                     history, checked, snap,
                     finites=finites if finites else None)
-                snap = jax.device_get(self._rebuild_state(
-                    state, params_C, opt_C, key, r0 + done, sim_time,
-                    iters, stream))
+                with TraceAnnotation("defl.snapshot"):
+                    snap = jax.device_get(self._rebuild_state(
+                        state, params_C, opt_C, key, r0 + done, sim_time,
+                        iters, stream))
             rec = history[-1]
             k = rec.round - r0
             at_boundary = k % eval_every == 0 or k == max_rounds
             if self.eval_fn and at_boundary:
-                ev = self.eval_fn(self._params_from(params_C))
+                with TraceAnnotation("defl.eval"):
+                    ev = self.eval_fn(self._params_from(params_C))
                 rec.test_acc = float(ev.get("acc", np.nan))
                 rec.test_loss = float(ev.get("loss", np.nan))
                 if (target_acc and rec.test_acc is not None
                         and rec.test_acc >= target_acc):
                     stop = True
-        new_state = self._rebuild_state(
-            state, params_C, opt_C, key, r0 + len(history), sim_time,
-            iters, stream)
+        with TraceAnnotation("defl.snapshot"):
+            new_state = self._rebuild_state(
+                state, params_C, opt_C, key, r0 + len(history), sim_time,
+                iters, stream)
         return new_state, SimResult(
             history=history, params=self._params_from(params_C),
             label=self.label, fed=self.fed)
@@ -2094,6 +2121,7 @@ class Simulator:
                     rec.n_participants, int):
                 rec.n_participants = int(rec.n_participants)
 
+    @functools.partial(jax.profiler.annotate_function, name="defl.run")
     def run(
         self,
         state: SimState,
