@@ -71,7 +71,8 @@ def envelope_local_steps_fn(loss_fn: Callable, opt: Optimizer):
                    the masked loss; loss_fn(params, batch, sample_mask, n)
                    must make padded samples exact zeros in the loss and
                    its gradient (e.g. models.cnn.cnn_loss_masked, whose
-                   conv backward is pad-stable via `_ps_matmul`)
+                   conv backward is pad-stable via `_ps_matmul` or
+                   `_ps_conv`)
 
     The returned mean loss accumulates in the scan carry exactly like
     `local_steps_fn`'s (padded steps add an exact 0) and divides by the

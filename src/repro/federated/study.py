@@ -25,9 +25,10 @@ fleet over the (arm x seed) member axis:
     padded to the group's (V_env, B_env) = (max V, max b) under traced
     validity masks. Padded local steps are in-graph no-ops (`where`
     state keeps), padded samples carry exact-zero loss/gradient
-    contributions (models.cnn.cnn_loss_masked + the pad-stable `_ps_matmul`
-    conv backward), and the native simulator runs the SAME envelope-form
-    graph at the trivial all-ones masks — so each member's history and
+    contributions (models.cnn.cnn_loss_masked + the pad-stable conv
+    backward, `_ps_matmul` on the CPU and `_ps_conv` on the TPU), and the
+    native simulator runs the SAME envelope-form graph at the trivial
+    all-ones masks — so each member's history and
     trained params are bit-identical to its own sequential
     `Simulator.run()` (tests/test_study.py).
   * `target_acc` / `max_sim_time` work per member through the device-side

@@ -1,5 +1,6 @@
-"""Pallas kernels of the FL path (quantize) and of the model stack
-(flash attention, selective scan). Each has kernel.py, a jit'd ops.py
+"""Pallas kernels of the FL path (quantize), of the CNN's conv filter
+gradient on the TPU (conv_dw) and of the model stack (flash attention,
+selective scan). Each has kernel.py, a jit'd ops.py
 wrapper, and a plain-jnp ref.py oracle."""
 import jax
 

@@ -9,6 +9,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.conv_dw.ops import conv_filter_grad
+
 
 @dataclass(frozen=True)
 class CNNConfig:
@@ -126,16 +128,64 @@ def _patches(x, k):
     return jnp.concatenate(cols, axis=-1)
 
 
-def _conv(x, p):
-    # im2col + matmul rather than conv_general_dilated: XLA:CPU lowers the
-    # filter/input gradients of a direct conv to transposed convolutions
-    # that run ~10-25x slower than the forward pass; the patches+dot form
-    # keeps both directions on the (fast) GEMM path and is bit-identical in
-    # the forward direction. The FL simulator spends nearly all its compute
-    # here (V fwd/bwd passes per client per round).
+def _conv_fwd(x, w):
+    """'SAME' stride-1 conv of x (B, H, W, C) with an HWIO filter, at the
+    default matmul precision."""
+    return jax.lax.conv_general_dilated(
+        x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+
+@jax.custom_vjp
+def _ps_conv(x, w):
+    """`_conv_fwd` with a *pad-stable* backward, as `_ps_matmul` is for the
+    im2col path. dx is XLA's own transposed conv. dW is summed sample by
+    sample, in sample order, by the Pallas kernel of
+    `kernels.conv_dw` on patches built in the backward only, so a batch
+    padded with zero-cotangent samples reproduces the unpadded filter
+    gradient, and the sum's order does not depend on how the compiler
+    tiles the program around it (PERF.md §6 compares the dW forms)."""
+    return _conv_fwd(x, w)
+
+
+def _ps_conv_fwd(x, w):
+    return _conv_fwd(x, w), (x, w)
+
+
+def _ps_conv_bwd(res, dy):
+    x, w = res
+    dx, = jax.vjp(lambda x: _conv_fwd(x, w), x)[1](dy)
+    return dx, conv_filter_grad(x, dy, w.shape[0])
+
+
+_ps_conv.defvjp(_ps_conv_fwd, _ps_conv_bwd)
+
+
+def _conv_im2col(x, p):
     k = p["w"].shape[0]
     w = p["w"].reshape(-1, p["w"].shape[-1])  # (k*k*C, O)
     return _ps_matmul(_patches(x, k), w) + p["b"]
+
+
+def _conv_xla(x, p):
+    return _ps_conv(x, p["w"]) + p["b"]
+
+
+def _conv(x, p):
+    # The lowering is chosen by the platform the code is compiled for.
+    # TPU: XLA's own convolution (`_conv_xla`). im2col writes k*k shifted
+    # copies of every activation to HBM (and pads MNIST's one input channel
+    # 128x on the minor dimension), which took most of the local steps' and
+    # the eval's time there; the forward and dx convs read the activations
+    # in place, and only dW builds patches, in the backward, for the Pallas
+    # kernel that sums it sample by sample (`_ps_conv`).
+    # Every other platform (the CPU): im2col + matmul (`_conv_im2col`).
+    # XLA:CPU lowers the filter/input gradients of a direct conv to
+    # transposed convolutions that run ~10-25x slower than the forward
+    # pass; the patches+dot form keeps both directions on the GEMM path.
+    # Both paths keep the parameter gradients pad-stable (`_ps_matmul`,
+    # `_ps_conv`), which the Study's (V, b)-envelope relies on.
+    return jax.lax.platform_dependent(x, p, tpu=_conv_xla,
+                                      default=_conv_im2col)
 
 
 def _maxpool(x):

@@ -1,6 +1,7 @@
 """Compiles for a described TPU v5e chip, with no chip attached: the
-quantize kernel and the main-path scan chunks at `mnist_cnn`'s published
-widths. The TPU compiler refuses here what it would refuse on the chip
+quantize kernel, the main-path scan chunks and the eval's forward at
+`mnist_cnn`'s published widths, with the CNN's convs lowered as they are
+for the TPU. The TPU compiler refuses here what it would refuse on the chip
 (tiling, VMEM, device memory); nothing runs, so this says nothing about
 results or times.
 
@@ -12,6 +13,7 @@ import dataclasses
 import functools
 import math
 import os
+import re
 import sys
 
 import jax
@@ -70,9 +72,13 @@ def test_quantize_kernel_compiles_at_mnist_cnn_rows(one_chip):
     assert "tpu_custom_call" in fn.lower(x, x).compile().as_text()
 
 
-def test_mnist_paper_scan_chunk_compiles(one_chip):
-    compiled = _compile_chunk(experiment.get("mnist_paper"), one_chip)
-    mem = compiled.memory_analysis()
+@pytest.fixture(scope="module")
+def mnist_chunk(one_chip):
+    return _compile_chunk(experiment.get("mnist_paper"), one_chip)
+
+
+def test_mnist_paper_scan_chunk_compiles(mnist_chunk):
+    mem = mnist_chunk.memory_analysis()
     # Well inside the 16 GB of one v5e chip.
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 8e9
 
@@ -86,3 +92,72 @@ def test_compressed_pallas_chunk_compiles_to_mosaic(one_chip, monkeypatch):
         base.fed, compress_updates=True))
     compiled = _compile_chunk(spec, one_chip)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def mnist_chunk_im2col(one_chip):
+    """The same chunk with the convs lowered as im2col + matmul, as the
+    TPU lowered them before `_conv` chose by platform."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(cnn, "_conv", cnn._conv_im2col)
+        return _compile_chunk(experiment.get("mnist_paper"), one_chip)
+
+
+def _concat_operands(hlo: str) -> list:
+    """Operand count of every `concatenate` in compiled HLO text. The
+    operand list is read up to its matching ')', so parentheses inside
+    shapes and layouts (`T(8,128)`) do not cut it."""
+    counts = []
+    for m in re.finditer(r" concatenate\(", hlo):
+        depth, j = 1, m.end()
+        while depth:
+            depth += {"(": 1, ")": -1}.get(hlo[j], 0)
+            j += 1
+        counts.append(len(re.findall(r"%[\w.\-]+", hlo[m.end():j - 1])))
+    return counts
+
+
+def _patch_concats(hlo: str) -> int:
+    """25-operand `concatenate`s: the shifted slices of a 5x5 patch."""
+    return _concat_operands(hlo).count(25)
+
+
+def _has_5x5_conv(hlo: str) -> bool:
+    return re.search(r"convolution\([^\n]*window=\{size=5x5", hlo) is not None
+
+
+def test_patch_counter_finds_im2col_patches(mnist_chunk_im2col):
+    """The counter below sees im2col's patches: one concatenate per conv
+    layer in the forward, and more in the backward."""
+    hlo = mnist_chunk_im2col.as_text()
+    assert _patch_concats(hlo) >= 3
+    assert not _has_5x5_conv(hlo)
+
+
+def test_mnist_paper_chunk_runs_xla_convs(mnist_chunk, mnist_chunk_im2col):
+    """On the TPU the 5x5 convs lower to XLA convolutions, and each conv
+    layer's filter gradient runs in the Pallas kernel. Patches remain only
+    as the kernel's input, in the backward: fewer patch concatenates than
+    im2col builds, and fewer temporary bytes."""
+    hlo, ref = mnist_chunk.as_text(), mnist_chunk_im2col.as_text()
+    assert _has_5x5_conv(hlo)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert _patch_concats(hlo) < _patch_concats(ref)
+    temp = mnist_chunk.memory_analysis().temp_size_in_bytes
+    assert temp < mnist_chunk_im2col.memory_analysis().temp_size_in_bytes
+
+
+def test_mnist_cnn_eval_forward_builds_no_patches(one_chip):
+    """The eval's forward (`cnn_forward` on a test batch) holds XLA
+    convolutions and no patches at all."""
+    cfg = cnn.mnist_cnn()
+    params = jax.eval_shape(lambda k: cnn.init_cnn(cfg, k),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct((1000, *cfg.input_hw, cfg.in_channels),
+                             jnp.float32, sharding=one_chip)
+    fwd = jax.jit(functools.partial(cnn.cnn_forward, cfg))
+    hlo = fwd.lower(params, x).compile().as_text()
+    assert _has_5x5_conv(hlo)
+    assert _patch_concats(hlo) == 0
