@@ -6,10 +6,21 @@ by name and never listed in code, so a later cell adds files and edits
 none:
 
     chipbench/configs/<config>.json    model, data and FL constants
+    chipbench/families/<family>.py     the model family a configuration
+                                       names: its architecture keys, data,
+                                       reference math and FLOPs
+                                       (chipbench/families/__init__.py)
     chipbench/traffic/<traffic>.json   population, scenario, uplink, cadence
     chipbench/limits/<workload>.json   the limit of each number `correct`
                                        compares (chipbench/check.py)
     chipbench/metrics/<metric>.py      one per-layer metric reader
+
+A configuration of a new model adds, and edits nothing that is there:
+  1. configs/<config>.json, with "family": "<family>" and its sizes;
+  2. families/<family>.py, unless a family that is there already fits;
+  3. traffic/<traffic>.json for each of its cells;
+  4. limits/<workload>.json for each cell, from its readings;
+  5. metrics/<metric>.py for each per-layer metric no reader reads yet.
 
 Unknown keys are refused, so a misspelt knob cannot fall back to a
 default unseen.
@@ -17,19 +28,20 @@ default unseen.
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
+import sys
 from dataclasses import dataclass
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Dict, List, Optional
 
 REPO = Path(__file__).resolve().parent.parent
 BENCH_DIR = "chipbench"
 
-CONFIG_KEYS = {"name", "source", "model", "architecture", "params",
-               "dataset", "n_train", "n_test", "data_seed", "fl", "dtype",
-               "matmul_precision", "reduced", "assumed", "notes"}
-ARCH_KEYS = {"input_hw", "in_channels", "n_classes", "conv_channels",
-             "kernel", "fc_dim"}
+CONFIG_KEYS = {"name", "source", "family", "model", "architecture",
+               "params", "dataset", "n_train", "n_test", "data_seed", "fl",
+               "dtype", "matmul_precision", "reduced", "assumed", "notes"}
 FL_KEYS = {"epsilon", "nu", "c", "lr", "plan", "batch_cap", "alpha"}
 TRAFFIC_KEYS = {"name", "population", "scenario", "compress_updates",
                 "impl", "backend", "eval_every", "shard_clients", "chips",
@@ -67,6 +79,7 @@ class Cell:
     traffic: Dict
     limits: Dict[str, float]
     per_layer: List[Dict]
+    family: ModuleType
 
     @property
     def cohort(self) -> Optional[int]:
@@ -91,9 +104,35 @@ def load_benchmark(repo: Path = REPO) -> Dict:
     return _read_json(Path(repo) / "BENCHMARK.json")
 
 
-def _check_config(cfg: Dict, where: str) -> None:
+_FAMILIES: Dict[Path, ModuleType] = {}
+
+
+def load_family(name: str, repo: Path = REPO) -> ModuleType:
+    """The module chipbench/families/<name>.py of the checkout `repo`,
+    loaded once per process. The name is a plain identifier, so the
+    file lies in that directory."""
+    if not isinstance(name, str) or not name.isidentifier():
+        raise CellError(f"model family {name!r} is no plain identifier")
+    path = (Path(repo) / BENCH_DIR / "families" / f"{name}.py").resolve()
+    mod = _FAMILIES.get(path)
+    if mod is not None:
+        return mod
+    if not path.is_file():
+        raise CellError(f"no model family {name!r}: no file {path}")
+    # Registered, as dataclasses look their module up there.
+    modname = f"chipbench_family_{name}"
+    spec = importlib.util.spec_from_file_location(modname, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[modname] = mod
+    spec.loader.exec_module(mod)
+    _FAMILIES[path] = mod
+    return mod
+
+
+def _check_config(cfg: Dict, where: str, family: ModuleType) -> None:
     _refuse_unknown(where, cfg, CONFIG_KEYS)
-    _refuse_unknown(f"{where} architecture", cfg["architecture"], ARCH_KEYS)
+    _refuse_unknown(f"{where} architecture", cfg["architecture"],
+                    family.ARCH_KEYS)
     _refuse_unknown(f"{where} fl", cfg["fl"], FL_KEYS)
     if cfg["dtype"] != "float32":
         raise CellError(f"{where}: dtype {cfg['dtype']!r}; the reference "
@@ -132,7 +171,11 @@ def from_files(name: str, config_file: Path, traffic: str, chips: int,
     file, whether or not BENCHMARK.json lists it yet."""
     root = Path(repo) / BENCH_DIR
     cfg = _read_json(config_file)
-    _check_config(cfg, str(config_file))
+    if "family" not in cfg:
+        raise CellError(f"{config_file}: no \"family\" key; it names "
+                        f"{BENCH_DIR}/families/<family>.py")
+    family = load_family(cfg["family"], repo)
+    _check_config(cfg, str(config_file), family)
     tr_path = root / "traffic" / f"{traffic}.json"
     tr = _read_json(tr_path)
     _check_traffic(tr, str(tr_path))
@@ -143,7 +186,8 @@ def from_files(name: str, config_file: Path, traffic: str, chips: int,
     lim = _read_json(lim_path)
     _refuse_unknown(str(lim_path), lim, LIMIT_KEYS)
     return Cell(name=name, chips=int(chips), config=cfg, traffic=tr,
-                limits=dict(lim["limits"]), per_layer=list(per_layer))
+                limits=dict(lim["limits"]), per_layer=list(per_layer),
+                family=family)
 
 
 def dataset_name(cell: Cell) -> str:

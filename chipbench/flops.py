@@ -1,11 +1,6 @@
-"""Operations and bytes of the cells' work, counted from shapes.
-
-The FedAvg CNN's forward pass per sample, in multiply-accumulates:
-conv1 H*W*k*k*C_in*c1, conv2 (H/2)*(W/2)*k*k*c1*c2 (after one 2x2 pool),
-fc1 flat*fc, fc2 fc*classes, with flat = (H/4)*(W/4)*c2. A MAC is 2
-FLOPs; a training sample costs its forward pass and a backward pass of
-twice that (gradients of activations and of weights), 6 FLOPs a MAC.
-Bias adds, ReLU, pooling and the softmax are left out.
+"""Bytes of the cells' kernels, counted from shapes, and the chips'
+published peaks. A model family's operations are counted in its own
+module (chipbench/families/<family>.py).
 
 A kernel's bytes are its arguments' and outputs' bytes, from the shapes
 and dtypes written in the operation's own HLO text in the trace. On the
@@ -23,26 +18,6 @@ from pathlib import Path
 from typing import Dict
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
-
-
-def forward_macs(arch: Dict) -> int:
-    h, w = arch["input_hw"]
-    k, cin = arch["kernel"], arch["in_channels"]
-    c1, c2 = arch["conv_channels"]
-    flat = (h // 4) * (w // 4) * c2
-    return (h * w * k * k * cin * c1
-            + (h // 2) * (w // 2) * k * k * c1 * c2
-            + flat * arch["fc_dim"]
-            + arch["fc_dim"] * arch["n_classes"])
-
-
-def train_flops_per_sample(arch: Dict) -> int:
-    return 6 * forward_macs(arch)
-
-
-def eval_flops_per_sample(arch: Dict) -> int:
-    return 2 * forward_macs(arch)
-
 
 DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
                "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,

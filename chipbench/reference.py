@@ -1,25 +1,26 @@
 """Plain reference of what a cell's timed path computes.
 
-Federated averaging of the FedAvg CNN (McMahan et al., AISTATS 2017:
-5x5 conv 32, 2x2 max-pool, 5x5 conv 64, 2x2 max-pool, FC 512, FC to the
-classes, ReLU between), written from the description in straightforward
-`jax.numpy`: `lax.conv_general_dilated` and `jnp.dot`, plain SGD, a
+Federated averaging of the cell's model family (chipbench/families/),
+written in straightforward `jax.numpy`: the family's loss, plain SGD, a
 participation-weighted mean, float32 throughout, the convolutions and
 matmuls at the precision the configuration states (`matmul_precision`:
 "default" is the TPU's one bfloat16 pass with float32 accumulation,
-"highest" full float32). It imports nothing
-of the program. Its host side draws, from the run's seed, the same
+"highest" full float32). Only the family's trained weights are
+differentiated, sent and averaged; its frozen weights are held once,
+shared by every lane, and never change. It imports nothing of the
+program. Its host side draws, from the run's seed, the same
 per-round inputs the simulator's host draws (the seeded partition of
-the training rows, each client's epoch-shuffled batches, the cohort and
-the dropout and link-failure masks), and its int8 uplink is the
-stochastic-rounding quantizer (one float32 scale per 1,024-value row)
-fed the same per-client key schedule as the simulator. So one reference
-round sees what one program round sees, and their results differ only
-by the arithmetic.
+the training rows by their labels, each client's epoch-shuffled batches,
+the cohort and the dropout and link-failure masks), and its int8 uplink
+is the stochastic-rounding quantizer (one float32 scale per 1,024-value
+row) fed the same per-client key schedule as the simulator. So one
+reference round sees what one program round sees, and their results
+differ only by the arithmetic.
 
 `Reference(..., dtype=jnp.bfloat16)` is the control: the same rounds
-with weights, data and every operation in bfloat16. `fault=` plants a
-fault in the reference, which the tests then put in the program's place:
+with weights, floating-point data and every operation in bfloat16.
+`fault=` plants a fault in the reference, which the tests then put in
+the program's place:
 
     "frozen"       a round that returns its state unchanged
     "half_batch"   each local step drops half its batch, the mean taken
@@ -41,60 +42,6 @@ ROW = 1024  # values per float32 quantizer scale
 HIGHEST = lax.Precision.HIGHEST
 PRECISIONS = {"default": lax.Precision.DEFAULT, "highest": HIGHEST}
 FAULTS = ("frozen", "half_batch", "no_exchange")
-
-
-# -- model -------------------------------------------------------------------
-
-def init_params(arch: Dict, key) -> Dict:
-    """He-normal weights and zero biases, drawn in the order conv1, conv2,
-    fc1, fc2 from one split of `key`."""
-    k1, k2, k3, k4 = jax.random.split(key, 4)
-    c1, c2 = arch["conv_channels"]
-    k, cin = arch["kernel"], arch["in_channels"]
-    h, w = arch["input_hw"]
-    flat = (h // 4) * (w // 4) * c2
-
-    def he(key, shape, fan_in):
-        return jax.random.normal(key, shape, jnp.float32) * (2.0 / fan_in) ** 0.5
-
-    return {
-        "conv1": {"w": he(k1, (k, k, cin, c1), k * k * cin),
-                  "b": jnp.zeros((c1,), jnp.float32)},
-        "conv2": {"w": he(k2, (k, k, c1, c2), k * k * c1),
-                  "b": jnp.zeros((c2,), jnp.float32)},
-        "fc1": {"w": he(k3, (flat, arch["fc_dim"]), flat),
-                "b": jnp.zeros((arch["fc_dim"],), jnp.float32)},
-        "fc2": {"w": he(k4, (arch["fc_dim"], arch["n_classes"]),
-                        arch["fc_dim"]),
-                "b": jnp.zeros((arch["n_classes"],), jnp.float32)},
-    }
-
-
-def forward(p: Dict, x, precision):
-    """Logits of images x (N, H, W, C)."""
-    dn = ("NHWC", "HWIO", "NHWC")
-
-    def conv(x, q):
-        y = lax.conv_general_dilated(x, q["w"], (1, 1), "SAME",
-                                     dimension_numbers=dn,
-                                     precision=precision)
-        return y + q["b"]
-
-    def pool(x):
-        return lax.reduce_window(x, -jnp.inf, lax.max,
-                                 (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
-
-    x = pool(jax.nn.relu(conv(x, p["conv1"])))
-    x = pool(jax.nn.relu(conv(x, p["conv2"])))
-    x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(jnp.dot(x, p["fc1"]["w"], precision=precision)
-                    + p["fc1"]["b"])
-    return jnp.dot(x, p["fc2"]["w"], precision=precision) + p["fc2"]["b"]
-
-
-def loss_fn(p, x, y, precision):
-    logp = jax.nn.log_softmax(forward(p, x, precision), axis=-1)
-    return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=-1))
 
 
 # -- int8 uplink ---------------------------------------------------------------
@@ -138,30 +85,41 @@ def client_keys(key, n: int):
 
 # -- one round -----------------------------------------------------------------
 
+def _cast(x, dtype):
+    """Floating-point data in `dtype`; token ids and labels as they are."""
+    return x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x
+
+
 @functools.lru_cache(maxsize=None)
-def round_fn(lr: float, quantize: bool, precision, dtype_name: str,
-             fault: Optional[str], shards: int):
-    """The jitted round: every lane runs its V local SGD steps from the
-    global model, then the participation-weighted mean (over the int8
-    uplink when `quantize`)."""
+def round_fn(family, lr: float, quantize: bool, precision, dtype_name: str,
+             fault: Optional[str], shards: int, sequential: bool):
+    """The jitted round: every lane runs its V local SGD steps of the
+    trained weights from the global model, the lanes vmapped or, where
+    `sequential`, one after another; then the participation-weighted
+    mean (over the int8 uplink when `quantize`)."""
     dtype = jnp.dtype(dtype_name)
 
-    def lane(p, xb, yb):
+    def lane(p, frozen, xb, yb):
         if fault == "half_batch":
             xb, yb = xb[:, : xb.shape[1] // 2], yb[:, : yb.shape[1] // 2]
 
         def step(q, batch):
             x, y = batch
-            loss, g = jax.value_and_grad(loss_fn)(q, x, y, precision)
+            loss, g = jax.value_and_grad(family.loss)(q, frozen, x, y,
+                                                      precision)
             return jax.tree.map(lambda a, b: (a - lr * b).astype(dtype),
                                 q, g), loss
 
         q, losses = lax.scan(step, p, (xb, yb))
         return q, jnp.mean(losses)
 
-    def run(p, xb, yb, weights, mask, key):
-        xb = xb.astype(dtype)
-        new, losses = jax.vmap(lane, in_axes=(None, 0, 0))(p, xb, yb)
+    def run(p, frozen, xb, yb, weights, mask, key):
+        xb = _cast(xb, dtype)
+        if sequential:
+            new, losses = lax.map(lambda a: lane(p, frozen, *a), (xb, yb))
+        else:
+            new, losses = jax.vmap(lane, in_axes=(None, None, 0, 0))(
+                p, frozen, xb, yb)
         wm = weights.astype(jnp.float32) * mask
         s = jnp.sum(wm)
         wn = wm / jnp.where(s > 0, s, 1.0)
@@ -201,10 +159,10 @@ def round_fn(lr: float, quantize: bool, precision, dtype_name: str,
 
 
 @functools.lru_cache(maxsize=None)
-def _accuracy_fn(precision):
+def _eval_fn(family, precision):
     @jax.jit
-    def hits(p, x, y):
-        return jnp.sum(jnp.argmax(forward(p, x, precision), -1) == y)
+    def hits(p, frozen, x, y):
+        return family.eval_hits(p, frozen, x, y, precision)
 
     return hits
 
@@ -213,8 +171,8 @@ def _accuracy_fn(precision):
 
 def dirichlet_parts(y: np.ndarray, n_classes: int, m: int, alpha: float,
                     seed: int) -> List[np.ndarray]:
-    """Label-Dirichlet split of the training rows over m clients, re-drawn
-    until no client is empty."""
+    """Label-Dirichlet split of the training rows (labels y, 0 to
+    n_classes - 1) over m clients, re-drawn until no client is empty."""
     rng = np.random.default_rng(seed)
     for _ in range(100):
         shares = [[] for _ in range(m)]
@@ -267,20 +225,21 @@ class Population:
     """The host side of the run: which clients train each round, on which
     rows, with which weights, and whose updates arrive."""
 
-    def __init__(self, cell, y: np.ndarray, seed: int, b: int, V: int):
+    def __init__(self, cell, labels: np.ndarray, n_labels: int, seed: int,
+                 b: int, V: int):
         cfg, tr = cell.config, cell.traffic
         pop = tr["population"]
         self.M = int(pop["M"])
         self.K = None if pop.get("K") is None else int(pop["K"])
         self.b, self.V, self.seed = int(b), int(V), int(seed)
-        n = len(y)
+        n = len(labels)
         if self.K is not None and self.M > n:
             size = min(64, n)
             self.sizes = np.full(self.M, size, np.float32)
             self._rows = lambda m: virtual_shard(n, m, size, seed)
         else:
-            parts = dirichlet_parts(y, cfg["architecture"]["n_classes"],
-                                    self.M, cfg["fl"]["alpha"], seed)
+            parts = dirichlet_parts(labels, n_labels, self.M,
+                                    cfg["fl"]["alpha"], seed)
             self.sizes = np.array([len(p) for p in parts], np.float32)
             self._rows = lambda m: parts[m]
         self._clients: Dict[int, ClientBatches] = {}
@@ -324,7 +283,8 @@ class Population:
 # -- the run ---------------------------------------------------------------------
 
 class Reference:
-    """A reference run of one cell at one seed, round by round."""
+    """A reference run of one cell at one seed, round by round; the lanes
+    run in sequence where the family's SEQUENTIAL_LANES says."""
 
     def __init__(self, cell, seed: int, data, test, b: int, V: int,
                  dtype=jnp.float32, fault: Optional[str] = None,
@@ -332,30 +292,31 @@ class Reference:
         if fault is not None and fault not in FAULTS:
             raise ValueError(f"unknown fault {fault!r}; have {FAULTS}")
         self.cell, self.seed = cell, int(seed)
+        self.family = family = cell.family
         self.dtype = jnp.dtype(dtype)
         self.precision = (PRECISIONS[cell.config["matmul_precision"]]
                           if self.dtype == jnp.float32 else None)
-        arch = cell.config["architecture"]
-        self.params = jax.tree.map(
-            lambda a: a.astype(self.dtype),
-            init_params(arch, jax.random.PRNGKey(self.seed)))
+        trained, frozen = family.init(cell.config["architecture"],
+                                      jax.random.PRNGKey(self.seed))
+        self.params, self.frozen = jax.tree.map(
+            lambda a: a.astype(self.dtype), (trained, frozen))
         self.key = jax.random.PRNGKey(self.seed)
-        self.x = jnp.asarray(data.x).astype(self.dtype)
-        self.test_x = jnp.asarray(test.x).astype(self.dtype)
+        self.x = _cast(jnp.asarray(data.x), self.dtype)
+        self.test_x = _cast(jnp.asarray(test.x), self.dtype)
         self.test_y = jnp.asarray(test.y)
-        self.pop = Population(cell, np.asarray(data.y), self.seed, b, V)
+        self.pop = Population(cell, *family.labels(data), self.seed, b, V)
         self.y = jnp.asarray(data.y)
-        self._round = round_fn(float(cell.config["fl"]["lr"]),
+        self._round = round_fn(family, float(cell.config["fl"]["lr"]),
                                bool(cell.traffic["compress_updates"]),
                                self.precision, self.dtype.name, fault,
-                               int(shards))
+                               int(shards), bool(family.SEQUENTIAL_LANES))
 
     def round(self) -> float:
         rows, weights, mask = self.pop.draw()
         rows = jnp.asarray(rows)
         self.params, loss, self.key = self._round(
-            self.params, self.x[rows], self.y[rows], jnp.asarray(weights),
-            jnp.asarray(mask), self.key)
+            self.params, self.frozen, self.x[rows], self.y[rows],
+            jnp.asarray(weights), jnp.asarray(mask), self.key)
         return loss
 
     def rounds(self, n: int) -> List[float]:
@@ -363,14 +324,15 @@ class Reference:
                                                   for _ in range(n)])]
 
     def accuracy(self, block: int = 1000) -> float:
-        hits = _accuracy_fn(self.precision)
+        hits = _eval_fn(self.family, self.precision)
         n = int(self.test_y.shape[0])
-        total = sum(int(hits(self.params, self.test_x[i:i + block],
-                             self.test_y[i:i + block]))
-                    for i in range(0, n, block))
-        return total / n
+        got = [hits(self.params, self.frozen, self.test_x[i:i + block],
+                    self.test_y[i:i + block]) for i in range(0, n, block)]
+        return (sum(int(h) for h, _ in got)
+                / sum(int(c) for _, c in got))
 
     def host_params(self) -> Any:
+        """The trained weights on the host, in float32."""
         return jax.tree.map(lambda a: np.asarray(a, np.float32),
                             jax.device_get(self.params))
 

@@ -8,14 +8,14 @@ asks for, it exits 2 and prints no result.
 
 Set-up builds the cell's `ExperimentSpec` and its `Simulator` through
 the program's public API, with data drawn by the benchmark's own
-generator (chipbench/data.py) from `--seed`, and drives it through the
-cell's first `check_steps` steps. A step is one window call,
-`Simulator.run(state, max_rounds=eval_every, eval_every=eval_every)`:
-one compiled chunk of `eval_every` rounds plus its eval, so the first
-step compiles (or loads from the persistent cache) everything the window
-runs. Their readings are what `correct` compares with the reference
-(chipbench/check.py). Then the window drives the same simulator and
-state call after call, each call under a host annotation, until
+generator (the model family's, chipbench/families/) from `--seed`, and
+drives it through the cell's first `check_steps` steps. A step is one
+window call, `Simulator.run(state, max_rounds=eval_every,
+eval_every=eval_every)`: one compiled chunk of `eval_every` rounds plus
+its eval, so the first step compiles (or loads from the persistent
+cache) everything the window runs. Their readings are what `correct`
+compares with the reference (chipbench/check.py). Then the window drives
+the same simulator and state call after call, each call under a host annotation, until
 `--seconds` have passed; the last call that started is allowed to end,
 and the window ends with it.
 
@@ -71,7 +71,6 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
 from chipbench import cells, check, flops, reference, trace  # noqa: E402
-from chipbench.data import DataMaker  # noqa: E402
 
 NO_CHIP = 2
 
@@ -80,7 +79,7 @@ class SimProgram:
     """The system under test: the cell's Simulator, built through
     `ExperimentSpec.build()`, and its run state."""
 
-    def __init__(self, cell: cells.Cell, seed: int, maker: DataMaker):
+    def __init__(self, cell: cells.Cell, seed: int, maker: Callable):
         from repro.federated import experiment, scenarios
 
         experiment.DATASETS[cells.dataset_name(cell)] = maker
@@ -117,7 +116,7 @@ class ReferenceProgram:
     """The reference put in the program's place: the control (a lower
     precision) or a planted fault, driven exactly like SimProgram."""
 
-    def __init__(self, cell: cells.Cell, seed: int, maker: DataMaker,
+    def __init__(self, cell: cells.Cell, seed: int, maker: Callable,
                  dtype=None, fault: Optional[str] = None):
         data, test = _datasets(cell, seed, maker)
         b, V = (cell.traffic["plan_expected"][k] for k in ("b", "V"))
@@ -146,10 +145,7 @@ def _check_program_matches(cell, spec, scenarios) -> None:
     """The program's model and scenario must be the ones the cell's files
     state: the reference is built from those files."""
     arch = cell.config["architecture"]
-    m = spec.model_config()
-    got = {"input_hw": list(m.input_hw), "in_channels": m.in_channels,
-           "n_classes": m.n_classes, "conv_channels": list(m.conv_channels),
-           "kernel": m.kernel, "fc_dim": m.fc_dim}
+    got = cell.family.program_architecture(spec.model_config())
     want = {k: (list(v) if isinstance(v, (list, tuple)) else v)
             for k, v in arch.items()}
     if got != want:
@@ -164,16 +160,16 @@ def _check_program_matches(cell, spec, scenarios) -> None:
                 f"failure {s.link_failure}; the traffic states {sc}")
 
 
-def _datasets(cell: cells.Cell, seed: int, maker: DataMaker):
+def _datasets(cell: cells.Cell, seed: int, maker: Callable):
     cfg = cell.config
     data = maker(int(cfg["n_train"]), seed=seed)
     return data, maker(int(cfg["n_test"]), seed=seed + 1, task=data)
 
 
-def data_maker(cell: cells.Cell) -> DataMaker:
-    arch = cell.config["architecture"]
-    return DataMaker(arch["input_hw"], arch["in_channels"], arch["n_classes"],
-                     cell.config["data_seed"])
+def data_maker(cell: cells.Cell) -> Callable:
+    """The family's generator of the cell's data, in the signature of the
+    program's dataset registry."""
+    return cell.family.data_maker(cell.config)
 
 
 @dataclasses.dataclass
@@ -302,8 +298,8 @@ def run_cell(cell: cells.Cell, seed: int, seconds: float, traced: bool,
             trace=reduced, window_s=window_s, chips=cell.chips,
             rounds=rounds, evals=calls, train_samples=samples,
             eval_samples=calls * int(cell.config["n_test"]),
-            train_flops_per_sample=flops.train_flops_per_sample(arch),
-            eval_flops_per_sample=flops.eval_flops_per_sample(arch),
+            train_flops_per_sample=cell.family.train_flops_per_sample(arch),
+            eval_flops_per_sample=cell.family.eval_flops_per_sample(arch),
             peaks=flops.peaks(devs[0].device_kind), retraces=retraces)
         metrics = {}
         for m in cell.per_layer:

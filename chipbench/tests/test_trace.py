@@ -8,6 +8,7 @@ from pathlib import Path
 import pytest
 
 from chipbench import flops, trace
+from chipbench.families import fedavg_cnn
 from chipbench.trace import Event, Trace
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -91,10 +92,10 @@ def test_flops_from_the_published_macs():
     mnist = {"input_hw": [28, 28], "in_channels": 1, "n_classes": 10,
              "conv_channels": [32, 64], "kernel": 5, "fc_dim": 512}
     cifar = dict(mnist, input_hw=[32, 32], in_channels=3)
-    assert flops.forward_macs(mnist) == 12_273_152
-    assert flops.forward_macs(cifar) == 17_667_072
-    assert flops.train_flops_per_sample(mnist) == 6 * 12_273_152
-    assert flops.eval_flops_per_sample(cifar) == 2 * 17_667_072
+    assert fedavg_cnn.forward_macs(mnist) == 12_273_152
+    assert fedavg_cnn.forward_macs(cifar) == 17_667_072
+    assert fedavg_cnn.train_flops_per_sample(mnist) == 6 * 12_273_152
+    assert fedavg_cnn.eval_flops_per_sample(cifar) == 2 * 17_667_072
     # cifar's update is 2110 rows of 1024 values, padded to 2304. In HBM:
     # the int8 output and the float32 noise; the values and the scales
     # are staged in memory space 1.
