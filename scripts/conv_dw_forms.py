@@ -14,8 +14,11 @@ Each form replaces `models.cnn._conv` for the whole process:
             built in the backward, summed over the batch
   kernel    XLA conv, dW summed sample by sample in the Pallas kernel of
             `kernels.conv_dw` (the TPU lowering `_conv_xla`), up to 8
-            samples per grid step
+            samples per grid step, each sample's patches built in VMEM
   kernel_b1 the same kernel at one sample per grid step (TPU only)
+  kernel_hbm the same sample-ordered sum over the patch tensor built by
+            XLA in HBM (`ops.patches_t`, `ops.dy_rows`), up to 8 samples
+            per grid step: the kernel's former input path (TPU only)
 
 For each form and model (`mnist_paper`, `cifar_paper`) it prints one JSON
 line: the scan chunk's time per round (one eval per chunk included, compile
@@ -80,6 +83,18 @@ def _dw_kernel_b1(x, dy, w):
                                     interpret=False, max_block_b=1)
 
 
+def _dw_kernel_hbm(x, dy, w):
+    from tests.test_kernels_conv_dw import _patch_tensor_dw
+    k, _, _, O = w.shape
+    B = x.shape[0]
+    block_b = max(d for d in range(1, min(B, 8) + 1) if B % d == 0)
+    bf16 = jnp.bfloat16
+    dw = _patch_tensor_dw(conv_dw_ops.patches_t(x.astype(bf16), k),
+                          conv_dw_ops.dy_rows(dy.astype(bf16), k), block_b,
+                          interpret=False)
+    return dw.reshape(w.shape)
+
+
 FORMS = {
     "im2col": cnn._conv_im2col,
     "autodiff": lambda x, p: cnn._conv_fwd(x, p["w"]) + p["b"],
@@ -87,6 +102,7 @@ FORMS = {
     "patches": _grouped(_dw_patches),
     "kernel": cnn._conv_xla,
     "kernel_b1": _grouped(_dw_kernel_b1),
+    "kernel_hbm": _grouped(_dw_kernel_hbm),
 }
 
 

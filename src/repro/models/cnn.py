@@ -140,7 +140,7 @@ def _ps_conv(x, w):
     """`_conv_fwd` with a *pad-stable* backward, as `_ps_matmul` is for the
     im2col path. dx is XLA's own transposed conv. dW is summed sample by
     sample, in sample order, by the Pallas kernel of
-    `kernels.conv_dw` on patches built in the backward only, so a batch
+    `kernels.conv_dw`, which builds each sample's patches in VMEM, so a batch
     padded with zero-cotangent samples reproduces the unpadded filter
     gradient, and the sum's order does not depend on how the compiler
     tiles the program around it (PERF.md §6 compares the dW forms)."""
@@ -176,7 +176,7 @@ def _conv(x, p):
     # copies of every activation to HBM (and pads MNIST's one input channel
     # 128x on the minor dimension), which took most of the local steps' and
     # the eval's time there; the forward and dx convs read the activations
-    # in place, and only dW builds patches, in the backward, for the Pallas
+    # in place, and dW's patches are built only in VMEM, inside the Pallas
     # kernel that sums it sample by sample (`_ps_conv`).
     # Every other platform (the CPU): im2col + matmul (`_conv_im2col`).
     # XLA:CPU lowers the filter/input gradients of a direct conv to

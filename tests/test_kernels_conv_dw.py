@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.kernels.conv_dw import ops
 from repro.kernels.conv_dw.kernel import conv_dw_kernel
@@ -12,16 +13,66 @@ from repro.kernels.conv_dw.ref import conv_dw_ref
 from repro.models import cnn
 
 
-@pytest.mark.parametrize("B,K,N,O,block_b", [(4, 25, 896, 32, 1),
-                                             (6, 75, 320, 64, 3),
-                                             (8, 1, 40, 2, 8)])
-def test_kernel_matches_ref(key, B, K, N, O, block_b):
-    kp, kd = jax.random.split(key)
-    p = jax.random.normal(kp, (B, K, N))
-    dy = jax.random.normal(kd, (B, N, O))
-    np.testing.assert_allclose(
-        np.asarray(conv_dw_kernel(p, dy, block_b=block_b)),
-        np.asarray(conv_dw_ref(p, dy)), rtol=1e-5, atol=1e-4)
+# (B, H, W, C, O, k, block_b): the first three are the (B, K, N, O) cases
+# the kernel was first tested at on patch tensors; then every CNN layer
+# (mnist_cnn, cifar_cnn, mnist_cnn_small, mnist_cnn_tiny's 1x1).
+LAYERS = [(4, 28, 28, 1, 32, 5, 1), (6, 20, 12, 3, 64, 5, 3),
+          (8, 5, 8, 1, 2, 1, 8),
+          (4, 28, 28, 1, 32, 5, 4), (4, 14, 14, 32, 64, 5, 2),
+          (2, 32, 32, 3, 32, 5, 2), (2, 16, 16, 32, 64, 5, 1),
+          (4, 28, 28, 1, 8, 5, 4), (4, 14, 14, 8, 16, 5, 4),
+          (4, 28, 28, 1, 1, 1, 4), (4, 14, 14, 1, 2, 1, 2)]
+
+
+def _inputs(key, B, H, W, C, O):
+    kx, kd = jax.random.split(key)
+    return (jax.random.normal(kx, (B, H, W, C)),
+            jax.random.normal(kd, (B, H, W, O)))
+
+
+def _patch_tensor_dw(p, dy, block_b, interpret=True):
+    """The kernel's former form: the same sample-ordered sum, over the
+    patch tensor `ops.patches_t` and the padded cotangent `ops.dy_rows`
+    read from HBM (also a form of scripts/conv_dw_forms.py)."""
+    def body(p_ref, dy_ref, dw_ref):
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            dw_ref[...] = jnp.zeros_like(dw_ref)
+
+        for s in range(p_ref.shape[0]):
+            dw_ref[...] += jnp.dot(p_ref[s], dy_ref[s],
+                                   preferred_element_type=jnp.float32)
+
+    B, K, N = p.shape
+    O = dy.shape[-1]
+    return pl.pallas_call(
+        body, grid=(B // block_b,),
+        in_specs=[pl.BlockSpec((block_b, K, N), lambda b: (b, 0, 0)),
+                  pl.BlockSpec((block_b, N, O), lambda b: (b, 0, 0))],
+        out_specs=pl.BlockSpec((K, O), lambda b: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((K, O), jnp.float32),
+        interpret=interpret)(p, dy)
+
+
+@pytest.mark.parametrize("B,H,W,C,O,k,block_b", LAYERS)
+def test_kernel_matches_ref(key, B, H, W, C, O, k, block_b):
+    x, dy = _inputs(key, B, H, W, C, O)
+    got = conv_dw_kernel(ops.padded_rows(x, k), ops.dy_rows(dy, k), k=k,
+                         block_b=block_b)
+    want = conv_dw_ref(ops.patches_t(x, k), ops.dy_rows(dy, k))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("B,H,W,C,O,k,block_b", LAYERS)
+def test_kernel_bits_match_patch_tensor_form(key, B, H, W, C, O, k, block_b):
+    """Patches assembled in VMEM give the bits of the patch tensor read
+    from HBM: the same per-sample products, summed in the same order."""
+    x, dy = _inputs(key, B, H, W, C, O)
+    got = conv_dw_kernel(ops.padded_rows(x, k), ops.dy_rows(dy, k), k=k,
+                         block_b=block_b)
+    want = _patch_tensor_dw(ops.patches_t(x, k), ops.dy_rows(dy, k), block_b)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("k,C,O,H", [(5, 1, 32, 28), (5, 32, 64, 14),

@@ -25,6 +25,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import chip_smoke as cs  # noqa: E402
 from repro.federated import compression, experiment  # noqa: E402
+from repro.kernels.conv_dw import ops as conv_dw_ops  # noqa: E402
 from repro.kernels.quantize import ops as q_ops  # noqa: E402
 from repro.kernels.quantize.kernel import quantize_kernel  # noqa: E402
 from repro.models import cnn  # noqa: E402
@@ -136,15 +137,44 @@ def test_patch_counter_finds_im2col_patches(mnist_chunk_im2col):
 
 def test_mnist_paper_chunk_runs_xla_convs(mnist_chunk, mnist_chunk_im2col):
     """On the TPU the 5x5 convs lower to XLA convolutions, and each conv
-    layer's filter gradient runs in the Pallas kernel. Patches remain only
-    as the kernel's input, in the backward: fewer patch concatenates than
-    im2col builds, and fewer temporary bytes."""
+    layer's filter gradient runs in the Pallas kernel, which builds its
+    patches in VMEM: fewer patch concatenates than im2col builds, and
+    fewer temporary bytes."""
     hlo, ref = mnist_chunk.as_text(), mnist_chunk_im2col.as_text()
     assert _has_5x5_conv(hlo)
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2
     assert _patch_concats(hlo) < _patch_concats(ref)
     temp = mnist_chunk.memory_analysis().temp_size_in_bytes
     assert temp < mnist_chunk_im2col.memory_analysis().temp_size_in_bytes
+
+
+# The same chunk while the filter gradient's kernel read its patches as a
+# tensor from HBM, built by XLA (`conv_dw_ops.patches_t`): 881,636,864 B of
+# temporaries (v5e compile, jax 0.9.0, libtpu 0.0.34).
+PATCH_TENSOR_CHUNK_TEMP_BYTES = 881_636_864
+
+
+def test_mnist_paper_chunk_holds_no_patch_tensor(mnist_chunk):
+    """No conv layer's patch tensor (k*k*C rows by H*Wr lanes: [.., 25,
+    896] and [.., 800, 336] in mnist_cnn) is a buffer of the chunk, nor the
+    target of a dynamic-update-slice; the kernel builds the patches in
+    VMEM. The temporaries fall below the patch-tensor chunk's by at least
+    conv2's tensor, 10 clients x b = 32 samples x 800 x 336 bf16 =
+    172,032,000 B (703,020,544 B, v5e compile)."""
+    cfg = cnn.mnist_cnn()
+    hlo = mnist_chunk.as_text()
+    k, (H, W), C = cfg.kernel, cfg.input_hw, cfg.in_channels
+    tensor_bytes = []
+    for C_out in cfg.conv_channels:
+        rows, lanes = k * k * C, H * conv_dw_ops._row_width(W, k)
+        shape = rf"\[[\d,]*\b{rows},{lanes}\]"
+        assert not re.search(shape, hlo), (rows, lanes)
+        assert not re.search(rf"= \w+{shape}[^\n]*dynamic-update-slice\(",
+                             hlo), (rows, lanes)
+        tensor_bytes.append(10 * 32 * rows * lanes * 2)
+        H, W, C = H // 2, W // 2, C_out
+    temp = mnist_chunk.memory_analysis().temp_size_in_bytes
+    assert temp <= PATCH_TENSOR_CHUNK_TEMP_BYTES - max(tensor_bytes)
 
 
 def test_mnist_cnn_eval_forward_builds_no_patches(one_chip):
